@@ -55,6 +55,7 @@ class BufState(NamedTuple):
     staged_n: jnp.ndarray   # (U,) int32
 
 
+@jax.named_scope("fifo_commit")
 def _stage_impl(state: BufState, x_new, y_new, counts) -> BufState:
     """Append ``counts[u]`` of client u's padded arrival rows to its staged
     buffer. Rows beyond counts[u] are padding and are dropped via an
@@ -73,6 +74,7 @@ def _stage_impl(state: BufState, x_new, y_new, counts) -> BufState:
 _stage = jax.jit(_stage_impl)
 
 
+@jax.named_scope("fifo_commit")
 def _commit_impl(state: BufState) -> BufState:
     """Apply all staged arrivals FIFO at the round boundary (one scatter).
     Row-local: safe to run per shard."""

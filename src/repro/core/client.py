@@ -56,6 +56,7 @@ def make_local_train_body(grad_fn: Callable, lr: float, kappa_max: int,
     exact same local-training math.
     """
 
+    @jax.named_scope("local_sgd")
     def one_client(global_params, batch_u, kappa_u):
         def body(params, inp):
             batch_t, t = inp

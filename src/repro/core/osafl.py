@@ -49,6 +49,7 @@ def make_stacked_round_body(fl: FLConfig, mesh=None):
         reduce = shard_map(reduce, mesh=mesh, in_specs=(P(axes, None), P()),
                            out_specs=(P(axes), P(axes), P()))
 
+    @jax.named_scope("aggregate")
     def rnd(w, buf, part_prev, lam_prev, d_new, active, alphas, key):
         part = part_prev | active
         buf = jnp.where(active[:, None], d_new, buf)

@@ -135,6 +135,7 @@ def make_solver_core(net: NetworkConfig, backend: str = "x64"):
     fracs = np.array([1.0, 0.1, 0.01, 1e-3, 1e-4])
     ks = np.arange(1.0, net.kappa_max + 1)          # (K,) candidate kappas
 
+    @jax.named_scope("resource_solve")
     def solve(c, s, f_max, p_max, e_bd, xi, gamma, n_params):
         xg = xi * gamma
         cc = net.n * net.nbar * c * s               # cycles per local round

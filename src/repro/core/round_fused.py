@@ -50,6 +50,13 @@ consumers and cost ~1.6x on the full round at U=256. Backends:
     bit-exact against the multi-dispatch engine when both are driven with
     the same device draws (the replay test).
 
+Each layer of the round runs under a ``jax.named_scope`` (``request_gen``,
+``fifo_commit``, ``local_sgd``, ``aggregate``, ``eval``, ``resource_solve``),
+placed in the traced bodies that the dispatch round shares, so the ops of
+both rounds name their layer in their ``op_name`` metadata and a profiler
+trace can be split by layer. (The dispatch round evaluates eagerly, op by
+op; eager ops take no scope from their caller.)
+
 ``FusedEngine`` owns one AOT-compiled executable per distinct segment
 length (``compiled_text`` exposes its optimized HLO for
 ``launch/hlo_analysis.dispatch_report``); ``repro/harness/experiments.py`` glues it
@@ -220,10 +227,11 @@ class FusedEngine:
             t = carry.t
             k_arr, _, k_slots = round_keys(base_key, t)
             # 1. arrivals: on-device Binomial counts + Gumbel-trick samples
-            counts = draw_counts(k_arr, p_ac, arrivals)
-            stream, xs, ys = _draw_block(consts, carry.stream, counts,
-                                         width=arrivals, warmup=0,
-                                         dataset=dataset, topk=topk)
+            with jax.named_scope("request_gen"):
+                counts = draw_counts(k_arr, p_ac, arrivals)
+                stream, xs, ys = _draw_block(consts, carry.stream, counts,
+                                             width=arrivals, warmup=0,
+                                             dataset=dataset, topk=topk)
             # 2. FIFO commit (the round-boundary scatter)
             buf = _commit_impl(_stage_impl(carry.buf, xs, ys, counts))
             # 3. this round's resource decisions, solved ahead of the scan
@@ -234,19 +242,21 @@ class FusedEngine:
             kappas = kap_t.astype(jnp.int32)
             active = kappas >= 1
             # 4. masked kappa_u-step local SGD over the whole cohort
-            slots = draw_slots(k_slots, buf.size, buf.head, buf.cap,
-                               (fl.kappa_max, batch))
-            uu = jnp.arange(p_ac.shape[0], dtype=jnp.int32
-                            ).reshape(-1, 1, 1)
-            batches = {"x": buf.x[uu, slots], "y": buf.y[uu, slots]}
-            d, _ = local(codec.unflatten(carry.w), batches, kappas)
-            upd = codec.flatten_stacked(d)
+            with jax.named_scope("local_sgd"):
+                slots = draw_slots(k_slots, buf.size, buf.head, buf.cap,
+                                   (fl.kappa_max, batch))
+                uu = jnp.arange(p_ac.shape[0], dtype=jnp.int32
+                                ).reshape(-1, 1, 1)
+                batches = {"x": buf.x[uu, slots], "y": buf.y[uu, slots]}
+                d, _ = local(codec.unflatten(carry.w), batches, kappas)
+                upd = codec.flatten_stacked(d)
             # 5. eq. 19-21 scored aggregation
             w, dbuf, part, lam_use, lam = srv_round(
                 carry.w, carry.d_buffer, carry.participated, carry.lam_prev,
                 upd, active, alphas, sketch_key)
             # 6. eval (inside the scan: per-round history, still 1 dispatch)
-            loss, m = small_loss(codec.unflatten(w), test_batch, model)
+            with jax.named_scope("eval"):
+                loss, m = small_loss(codec.unflatten(w), test_batch, model)
             out = {"test_loss": loss.astype(jnp.float32),
                    "test_acc": m["accuracy"].astype(jnp.float32),
                    "participants": jnp.sum(active).astype(jnp.int32),
@@ -258,6 +268,7 @@ class FusedEngine:
 
         return round_body
 
+    @jax.named_scope("resource_solve")
     def _solve_segment(self, ts):
         """All ``len(ts)`` rounds' channel draws + resource solves, batched
         over (rounds x U) lanes: ``(kappas (k, U) in the solve dtype,
@@ -336,7 +347,8 @@ class FusedEngine:
         carry and a dict of per-round output columns (length-leading)."""
         if length < 1:
             raise ValueError(f"segment length must be >= 1, got {length}")
-        return self._compiled(carry, int(length))(carry)
+        with jax.profiler.TraceAnnotation("osafl.dispatch"):
+            return self._compiled(carry, int(length))(carry)
 
     @staticmethod
     def check_outputs(outs: dict) -> None:

@@ -102,6 +102,7 @@ def _features_for(consts: StreamConsts, fids: jnp.ndarray) -> jnp.ndarray:
 
 
 @partial(jax.jit, static_argnames=("width", "warmup", "dataset", "topk"))
+@jax.named_scope("request_gen")
 def _draw_block(consts: StreamConsts, state: StreamState, counts,
                 width: int, warmup: int, dataset: int, topk: int):
     """Advance the cohort until every user u has emitted counts[u] samples

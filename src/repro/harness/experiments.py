@@ -23,6 +23,15 @@ two-tier edge-cluster aggregation in ``core/hierarchy.py`` (K=1 is the
 bit-exact flat-parity anchor); on the sparse engine the slot pool becomes K
 per-cluster blocks, participation sampling stratifies over the live cluster
 map, and the ``cluster_churn`` scenario drives membership moves.
+
+Tracing: the host loops of the dispatch round (stacked and pod engines)
+mark each round with ``StepTraceAnnotation("osafl.round", step_num=t)`` and
+its phases with ``TraceAnnotation("osafl.<phase>")`` spans (``admit``,
+``arrivals``, ``request_draw``, ``fifo_commit``, ``resource_solve``,
+``slot_sample``, ``local_sgd``, ``aggregate``, ``eval``, ``sync``); the
+device programs name their layers with ``jax.named_scope`` (see
+``core/round_fused.py``). Both land in a ``jax.profiler`` trace of a
+``run()`` call and record nothing while no profiler is running.
 """
 from __future__ import annotations
 
@@ -36,6 +45,7 @@ from types import SimpleNamespace
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro import checkpoint
 from repro.checkpoint import CheckpointError
@@ -519,88 +529,95 @@ def _draw_round_inputs(s: SimpleNamespace, xc: "ExperimentConfig",
     unscenarioed order (bit-exact, tests/test_scenarios.py)."""
     t0 = time.perf_counter()
     scn = s.scn
-    if (s.sparse and s.K >= 1 and scn is not None and scn.moves_clusters):
-        # membership churn first: this round's participation sample and
-        # admissions see the round-t cluster map. Movers re-seat in their
-        # new block immediately; like any admission, the reassigned slot's
-        # FIFO window resets to the incoming user's capacity.
-        mv = scn.round_cluster_moves(t, s.U, s.K)
-        if mv is not None:
-            moved, res = s.server.apply_cluster_moves(*mv)
-            if res is not None and res.newly.any():
-                s.sbuf.reset_rows(res.slots[res.newly],
-                                  s.caps[moved[res.newly]])
-    avail = scn.round_available(t, s.U) if scn is not None else None
-    sel = None
-    if s.sparse:
-        if s.resample:
-            weights = (scn.round_selection_weights(t, s.U)
-                       if scn is not None else None)
-            if s.K >= 1:
-                # stratified over the live cluster map; delegates verbatim
-                # to sample_participants at K=1 (RNG-stream parity)
-                sel = sample_participants_clustered(
-                    s.rng, s.server.assign, s.K, s.m_active, s.C // s.K,
-                    weights=weights, available=avail)
-            else:
-                sel = sample_participants(s.rng, s.U, s.m_active,
-                                          weights=weights, available=avail)
-            res = s.server.admit(sel)
-            if res.newly.any():
-                # a reassigned slot loses the evicted resident's dataset:
-                # reset its FIFO window to the incoming user's capacity
-                s.sbuf.reset_rows(res.slots[res.newly],
-                                  s.caps[sel[res.newly]])
-        cohort = s.server.cohort
-        p_ac = s.p_ac[cohort]
-    else:
-        cohort, p_ac = None, s.p_ac
-    e_u = xc.arrivals
-    if scn is not None:
-        e_u, p_ac = scn.round_arrivals(t, e_u, p_ac)
-    if avail is not None:
-        # departed users generate no arrivals this round
-        p_ac = p_ac * (avail[cohort] if s.sparse else avail)
-    counts = binomial_arrivals_batched(s.rng, e_u, p_ac)
-    if s.stacked_req:
+    with TraceAnnotation("osafl.admit"):
+        if (s.sparse and s.K >= 1 and scn is not None and scn.moves_clusters):
+            # membership churn first: this round's participation sample and
+            # admissions see the round-t cluster map. Movers re-seat in their
+            # new block immediately; like any admission, the reassigned slot's
+            # FIFO window resets to the incoming user's capacity.
+            mv = scn.round_cluster_moves(t, s.U, s.K)
+            if mv is not None:
+                moved, res = s.server.apply_cluster_moves(*mv)
+                if res is not None and res.newly.any():
+                    s.sbuf.reset_rows(res.slots[res.newly],
+                                      s.caps[moved[res.newly]])
+        avail = scn.round_available(t, s.U) if scn is not None else None
+        sel = None
         if s.sparse:
-            # the stacked stream state stays (U,)-wide; non-residents draw
-            # a zero count so their streams do not advance
-            full = np.zeros(s.U, counts.dtype)
-            full[cohort] = counts
-            xs, ys, cnt = s.rstream.draw(full, xc.dataset, s.arr_width)
-            arrivals = (xs[cohort], ys[cohort], cnt[cohort])
+            if s.resample:
+                weights = (scn.round_selection_weights(t, s.U)
+                           if scn is not None else None)
+                if s.K >= 1:
+                    # stratified over the live cluster map; delegates verbatim
+                    # to sample_participants at K=1 (RNG-stream parity)
+                    sel = sample_participants_clustered(
+                        s.rng, s.server.assign, s.K, s.m_active, s.C // s.K,
+                        weights=weights, available=avail)
+                else:
+                    sel = sample_participants(s.rng, s.U, s.m_active,
+                                              weights=weights, available=avail)
+                res = s.server.admit(sel)
+                if res.newly.any():
+                    # a reassigned slot loses the evicted resident's dataset:
+                    # reset its FIFO window to the incoming user's capacity
+                    s.sbuf.reset_rows(res.slots[res.newly],
+                                      s.caps[sel[res.newly]])
+            cohort = s.server.cohort
+            p_ac = s.p_ac[cohort]
         else:
-            arrivals = s.rstream.draw(counts, xc.dataset, s.arr_width)
-        jax.block_until_ready(arrivals[1])   # honest request_gen_s
-    else:
-        streams = ([s.streams[u] for u in cohort] if s.sparse
-                   else s.streams)
-        arrivals = draw_arrival_batch(streams, counts, xc.dataset,
-                                      width=s.arr_width)
-    req_s = time.perf_counter() - t0
-    s.sbuf.stage(*arrivals)
-    s.sbuf.commit()
-    if xc.use_resource_opt:
-        sysb = s.sysb
+            cohort, p_ac = None, s.p_ac
+    with TraceAnnotation("osafl.arrivals"):
+        e_u = xc.arrivals
         if scn is not None:
-            sysb = scn.round_system(t, sysb)
-        sysb = _gather_sys(sysb, cohort) if s.sparse else sysb
-        kappas = optimize_round_batched(s.rng, s.net, sysb, s.n_params,
-                                        backend=xc.resource_backend).kappa
-    else:
-        kappas = np.full(s.C, s.fl.kappa_max)
-    active = kappas >= 1                    # kappa = 0 => straggler
-    if avail is not None:
-        # departed users do not report an update either
-        active = active & (avail[cohort] if s.sparse else avail)
-    if sel is not None:
-        # only the sampled round-active users train; carried residents idle.
-        # A freshly admitted slot with zero arrivals has nothing to train on.
-        sel_mask = np.zeros(s.C, bool)
-        sel_mask[s.server.pool.user_slot[sel]] = True
-        active = active & sel_mask & (s.sbuf.sizes > 0)
-    slots = s.sbuf.sample_slots(s.rng, (s.fl.kappa_max, xc.batch))
+            e_u, p_ac = scn.round_arrivals(t, e_u, p_ac)
+        if avail is not None:
+            # departed users generate no arrivals this round
+            p_ac = p_ac * (avail[cohort] if s.sparse else avail)
+        counts = binomial_arrivals_batched(s.rng, e_u, p_ac)
+    with TraceAnnotation("osafl.request_draw"):
+        if s.stacked_req:
+            if s.sparse:
+                # the stacked stream state stays (U,)-wide; non-residents draw
+                # a zero count so their streams do not advance
+                full = np.zeros(s.U, counts.dtype)
+                full[cohort] = counts
+                xs, ys, cnt = s.rstream.draw(full, xc.dataset, s.arr_width)
+                arrivals = (xs[cohort], ys[cohort], cnt[cohort])
+            else:
+                arrivals = s.rstream.draw(counts, xc.dataset, s.arr_width)
+            jax.block_until_ready(arrivals[1])   # honest request_gen_s
+        else:
+            streams = ([s.streams[u] for u in cohort] if s.sparse
+                       else s.streams)
+            arrivals = draw_arrival_batch(streams, counts, xc.dataset,
+                                          width=s.arr_width)
+    req_s = time.perf_counter() - t0
+    with TraceAnnotation("osafl.fifo_commit"):
+        s.sbuf.stage(*arrivals)
+        s.sbuf.commit()
+    with TraceAnnotation("osafl.resource_solve"):
+        if xc.use_resource_opt:
+            sysb = s.sysb
+            if scn is not None:
+                sysb = scn.round_system(t, sysb)
+            sysb = _gather_sys(sysb, cohort) if s.sparse else sysb
+            kappas = optimize_round_batched(s.rng, s.net, sysb, s.n_params,
+                                            backend=xc.resource_backend).kappa
+        else:
+            kappas = np.full(s.C, s.fl.kappa_max)
+    with TraceAnnotation("osafl.slot_sample"):
+        active = kappas >= 1                    # kappa = 0 => straggler
+        if avail is not None:
+            # departed users do not report an update either
+            active = active & (avail[cohort] if s.sparse else avail)
+        if sel is not None:
+            # only the sampled round-active users train; carried residents
+            # idle. A freshly admitted slot with zero arrivals has nothing to
+            # train on.
+            sel_mask = np.zeros(s.C, bool)
+            sel_mask[s.server.pool.user_slot[sel]] = True
+            active = active & sel_mask & (s.sbuf.sizes > 0)
+        slots = s.sbuf.sample_slots(s.rng, (s.fl.kappa_max, xc.batch))
     return req_s, kappas, active, slots
 
 
@@ -720,36 +737,42 @@ def _run_stacked(alg: str, xc: "ExperimentConfig", eval_samples: int,
         history, start_round = _resume_stacked(s, snap)
     try:
         for t in range(start_round, xc.rounds):
-            t_start = time.perf_counter()
-            req_s, kappas, active, slots = _draw_round_inputs(s, xc, t)
-            d, w = local_step(s.server.params, s.sbuf.gather(slots),
-                              jnp.asarray(kappas))
-            upd = s.codec.flatten_stacked(w if s.weights_alg else d)
-            _server_round(s, alg, upd, active, kappas)
-            loss, m = small_loss(s.server.params, s.test_batch, s.model)
-            # round_s feeds the bench gates: block on every async output of
-            # the round (the server round's weights + the committed buffer),
-            # not just the eval loss
-            jax.block_until_ready((loss, s.server.w, s.sbuf.state))
-            history.append({"round": t, "test_loss": float(loss),
-                            "test_acc": float(m["accuracy"]),
-                            "participants": int(active.sum()),
-                            "request_gen_s": req_s,
-                            "round_s": time.perf_counter() - t_start})
-            if save_every_k and (t + 1) % save_every_k == 0:
-                writer.submit(
-                    checkpoint_path(checkpoint_dir, t + 1),
-                    {"engine": "stacked", "alg": alg,
-                     "config": _run_shape(xc, eval_samples),
-                     "next_round": t + 1,
-                     "rng": checkpoint.generator_state(s.rng),
-                     "server": s.server.state_dict(),
-                     "buffer": s.sbuf.state_dict(),
-                     "streams": (s.rstream.state_dict() if s.stacked_req
-                                 else streams_state_dict(s.streams)),
-                     "history": history},
-                    metadata={"engine": "stacked", "alg": alg,
-                              "round": t + 1})
+            with StepTraceAnnotation("osafl.round", step_num=t):
+                t_start = time.perf_counter()
+                req_s, kappas, active, slots = _draw_round_inputs(s, xc, t)
+                with TraceAnnotation("osafl.local_sgd"):
+                    d, w = local_step(s.server.params, s.sbuf.gather(slots),
+                                      jnp.asarray(kappas))
+                    upd = s.codec.flatten_stacked(w if s.weights_alg else d)
+                with TraceAnnotation("osafl.aggregate"):
+                    _server_round(s, alg, upd, active, kappas)
+                with TraceAnnotation("osafl.eval"):
+                    loss, m = small_loss(s.server.params, s.test_batch,
+                                         s.model)
+                # round_s feeds the bench gates: block on every async output
+                # of the round (the server round's weights + the committed
+                # buffer), not just the eval loss
+                with TraceAnnotation("osafl.sync"):
+                    jax.block_until_ready((loss, s.server.w, s.sbuf.state))
+                history.append({"round": t, "test_loss": float(loss),
+                                "test_acc": float(m["accuracy"]),
+                                "participants": int(active.sum()),
+                                "request_gen_s": req_s,
+                                "round_s": time.perf_counter() - t_start})
+                if save_every_k and (t + 1) % save_every_k == 0:
+                    writer.submit(
+                        checkpoint_path(checkpoint_dir, t + 1),
+                        {"engine": "stacked", "alg": alg,
+                         "config": _run_shape(xc, eval_samples),
+                         "next_round": t + 1,
+                         "rng": checkpoint.generator_state(s.rng),
+                         "server": s.server.state_dict(),
+                         "buffer": s.sbuf.state_dict(),
+                         "streams": (s.rstream.state_dict() if s.stacked_req
+                                     else streams_state_dict(s.streams)),
+                         "history": history},
+                        metadata={"engine": "stacked", "alg": alg,
+                                  "round": t + 1})
         if writer is not None:
             writer.close()          # drain barrier: all snapshots committed
     finally:
@@ -816,35 +839,43 @@ def _run_pod(alg: str, xc: "ExperimentConfig", pod_engine: str,
         history, start_round = _resume_stacked(s, snap)
     try:
         for t in range(start_round, xc.rounds):
-            t_start = time.perf_counter()
-            req_s, kappas, active, slots = _draw_round_inputs(s, xc, t)
-            d, w = pod_step(s.server.params, s.sbuf.state.x, s.sbuf.state.y,
-                            jnp.asarray(slots), jnp.asarray(kappas))
-            upd = s.codec.flatten_stacked(w if s.weights_alg else d)
-            _server_round(s, alg, upd, active, kappas)
-            loss, m = small_loss(s.server.params, s.test_batch, s.model)
-            # same fully-synced round_s convention as the vectorized harness
-            jax.block_until_ready((loss, s.server.w, s.sbuf.state))
-            history.append({"round": t, "test_loss": float(loss),
-                            "test_acc": float(m["accuracy"]),
-                            "participants": int(active.sum()),
-                            "request_gen_s": req_s,
-                            "round_s": time.perf_counter() - t_start})
-            if save_every_k and (t + 1) % save_every_k == 0:
-                writer.submit(
-                    checkpoint_path(checkpoint_dir, t + 1),
-                    {"engine": "pod", "alg": alg,
-                     "config": dict(_run_shape(xc, eval_samples),
-                                    **mesh_shape),
-                     "next_round": t + 1,
-                     "rng": checkpoint.generator_state(s.rng),
-                     "server": s.server.state_dict(),
-                     "buffer": s.sbuf.state_dict(),
-                     "streams": (s.rstream.state_dict() if s.stacked_req
-                                 else streams_state_dict(s.streams)),
-                     "history": history},
-                    metadata={"engine": "pod", "alg": alg, "round": t + 1,
-                              "pod_engine": pod_engine})
+            with StepTraceAnnotation("osafl.round", step_num=t):
+                t_start = time.perf_counter()
+                req_s, kappas, active, slots = _draw_round_inputs(s, xc, t)
+                with TraceAnnotation("osafl.local_sgd"):
+                    d, w = pod_step(s.server.params, s.sbuf.state.x,
+                                    s.sbuf.state.y, jnp.asarray(slots),
+                                    jnp.asarray(kappas))
+                    upd = s.codec.flatten_stacked(w if s.weights_alg else d)
+                with TraceAnnotation("osafl.aggregate"):
+                    _server_round(s, alg, upd, active, kappas)
+                with TraceAnnotation("osafl.eval"):
+                    loss, m = small_loss(s.server.params, s.test_batch,
+                                         s.model)
+                # same fully-synced round_s convention as the vectorized
+                # harness
+                with TraceAnnotation("osafl.sync"):
+                    jax.block_until_ready((loss, s.server.w, s.sbuf.state))
+                history.append({"round": t, "test_loss": float(loss),
+                                "test_acc": float(m["accuracy"]),
+                                "participants": int(active.sum()),
+                                "request_gen_s": req_s,
+                                "round_s": time.perf_counter() - t_start})
+                if save_every_k and (t + 1) % save_every_k == 0:
+                    writer.submit(
+                        checkpoint_path(checkpoint_dir, t + 1),
+                        {"engine": "pod", "alg": alg,
+                         "config": dict(_run_shape(xc, eval_samples),
+                                        **mesh_shape),
+                         "next_round": t + 1,
+                         "rng": checkpoint.generator_state(s.rng),
+                         "server": s.server.state_dict(),
+                         "buffer": s.sbuf.state_dict(),
+                         "streams": (s.rstream.state_dict() if s.stacked_req
+                                     else streams_state_dict(s.streams)),
+                         "history": history},
+                        metadata={"engine": "pod", "alg": alg,
+                                  "round": t + 1, "pod_engine": pod_engine})
         if writer is not None:
             writer.close()          # drain barrier: all snapshots committed
     finally:

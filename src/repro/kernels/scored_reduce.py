@@ -102,6 +102,7 @@ def _scored_reduce(d, mean, *, block_n, block_u, interpret):
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="scored_reduce",
     )(d, mean.reshape(1, Np))
     return dots[:U, 0], norms[:U, 0], msq[0, 0]
 

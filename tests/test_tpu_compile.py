@@ -76,6 +76,24 @@ def test_scored_reduce_default_mode_follows_platform(one_chip):
     assert "tpu_custom_call" not in cpu.compile().as_text()
 
 
+def test_scored_reduce_kernel_is_named_for_v5e(one_chip):
+    """The Mosaic call carries ``kernel_name = "scored_reduce"`` when lowered
+    and names its instruction ``%scored_reduce.N`` when compiled: the name
+    the profiler's trace shows for the kernel."""
+    U, N = 16, 4096
+    lowered = scored_reduce.lower(_spec((U, N), jnp.float32, one_chip),
+                                  _spec((N,), jnp.float32, one_chip),
+                                  interpret=False)
+    calls = [ln for ln in lowered.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert calls and all('kernel_name = "scored_reduce"' in ln
+                         for ln in calls)
+    compiled = [ln.strip() for ln in lowered.compile().as_text().splitlines()
+                if 'custom_call_target="tpu_custom_call"' in ln]
+    assert compiled and all(ln.removeprefix("ROOT ").startswith(
+        "%scored_reduce") for ln in compiled)
+
+
 def test_flash_attention_compiles_for_v5e(one_chip):
     B, H, Hkv, S, D = 1, 8, 2, 1024, 128
     compiled = flash_attention_bhsd.lower(
