@@ -27,7 +27,10 @@ module decouples the two scales:
     per-user tables; at admission the carried per-user state is gathered
     into the slot and the slot's contribution row is reset to the
     algorithm's refresh value (``init_row``) — slot-resident contributions
-    and datasets are *lost* on eviction, by design.
+    and datasets are *lost* on eviction, by design. The admission write is
+    one jitted program over the whole ``(C, N)`` buffer and ``(C,)`` slot
+    vectors (``make_slot_reset``), masked by the newly seated slots, so it
+    compiles once for any number of admitted users.
 
 Dense parity is the correctness anchor: with ``cohort_size = U`` the pool is
 the identity map, the inner server *is* the dense stacked server (same
@@ -47,6 +50,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.configs.base import FLConfig
 from repro.core.baselines import STACKED_SERVERS
@@ -241,8 +245,9 @@ class SlotPool:
 class CohortTables:
     """Persistent per-user ``(U,)``-leading tables under explicit
     ``NamedSharding`` over the mesh's client axes (``client_sharding``).
-    Without a mesh the tables are plain device arrays. Gather pulls cohort
-    rows into ``(C,)`` slot vectors; scatter writes slot results back."""
+    Without a mesh the tables are plain device arrays. Admission reads them
+    at the seated slots' users (``make_slot_reset``); scatter writes slot
+    results back."""
 
     def __init__(self, num_users: int, tables: dict, mesh=None):
         self.U = int(num_users)
@@ -264,10 +269,6 @@ class CohortTables:
 
     def __getitem__(self, k):
         return self._tables[k]
-
-    def gather(self, users) -> dict:
-        idx = jnp.asarray(np.asarray(users, np.int64))
-        return {k: jnp.take(v, idx, axis=0) for k, v in self._tables.items()}
 
     def scatter(self, users, values: dict) -> None:
         idx = jnp.asarray(np.asarray(users, np.int64))
@@ -297,6 +298,41 @@ class CohortTables:
                     f"{tuple(got.shape)}; the live run expects "
                     f"{tuple(cur.shape)}")
             self._tables[k] = self._put(jnp.asarray(got))
+
+
+def make_slot_reset(mesh=None):
+    """The admission write as one jitted program whose shapes do not depend
+    on how many users were seated:
+
+        reset_slots(buf, row, reset, slot_user, slot_state, tables)
+            -> (buf, slot_state, seated)
+
+    ``buf`` (C, N) is the slot contribution buffer, ``row`` (N,) the
+    refresh value, ``reset`` (C,) bool marks the newly seated slots and
+    ``slot_user`` (C,) the pool's slot -> user map (-1 = free: clamped, and
+    masked out by ``reset``). ``tables`` are the (U,) per-user tables; each
+    table is gathered at the slots' users and selected into the (C,)
+    ``slot_state`` vector of the same key where ``reset`` holds; the tables
+    with no slot state come back gathered (``seated``) for the host to fold
+    into its own copies. Every write is a select of the values a row
+    scatter would write, so the result is bit-identical to
+    ``x.at[slots].set(values)``, with no per-row loop whatever the buffer's
+    layout. No argument is donated: a checkpoint writer may still hold the
+    live buffer. Under a mesh the slot-indexed outputs take the client
+    sharding (``client_sharding``), the layout the round leaves them in."""
+
+    def reset_slots(buf, row, reset, slot_user, slot_state, tables):
+        src = jnp.maximum(slot_user, 0)
+        buf = jnp.where(reset[:, None], row[None, :], buf)
+        seated = {k: jnp.take(t, src, axis=0) for k, t in tables.items()}
+        slot_state = {k: jnp.where(reset, seated.pop(k), v)
+                      for k, v in slot_state.items()}
+        return buf, slot_state, seated
+
+    if mesh is None:
+        return jax.jit(reset_slots)
+    return jax.jit(reset_slots, out_shardings=(
+        client_sharding(mesh, 2), client_sharding(mesh, 1), None))
 
 
 class SparseCohortServer:
@@ -357,6 +393,7 @@ class SparseCohortServer:
             tables["scores"] = np.ones(self.U, np.float32)
             tables["lam_prev"] = np.ones(self.U, np.float32)
         self.tables = CohortTables(self.U, tables, mesh=mesh)
+        self._slot_reset = make_slot_reset(mesh)
         if not self.is_osafl:
             # sticky per-user metadata (loop "last seen update" semantics),
             # host-side like the inner servers' own copies
@@ -437,38 +474,48 @@ class SparseCohortServer:
         newly seated slot: carried per-user state is gathered from the
         tables, the contribution row is reset to the algorithm's refresh
         value (``init_row``) — the evicted resident's row is lost, which is
-        the documented eviction semantics. The caller owns the slot-indexed
-        *dataset* buffer and must reset the same slots
-        (``StackedOnlineBuffer.reset_rows``)."""
+        the documented eviction semantics. The device-side write is one call
+        of the fixed-shape ``make_slot_reset`` program, inside the host span
+        ``osafl.slot_reset`` whose ``rows`` stat counts the slots reset. The
+        caller owns the slot-indexed *dataset* buffer and must reset the
+        same slots (``StackedOnlineBuffer.reset_rows``)."""
         res = self.pool.admit(users)
         ns = res.slots[res.newly]
-        if ns.size == 0:
-            return res
-        nu = np.asarray(users, np.int64).ravel()[res.newly]
-        g = self.tables.gather(nu)
-        idx = jnp.asarray(ns)
-        row = self.inner.init_row()
-        if self.is_osafl:
-            self.inner.d_buffer = self.inner.d_buffer.at[idx].set(row)
-            self.inner.participated = self.inner.participated.at[idx].set(
-                g["participated"])
-            self.inner._lam_prev = self.inner._lam_prev.at[idx].set(
-                g["lam_prev"])
-            ls = np.array(self.inner.last_scores)
-            ls[ns] = np.asarray(g["scores"])
-            self.inner.last_scores = ls
-        else:
-            self.inner.buffer = self.inner.buffer.at[idx].set(row)
-            self.inner.participated[ns] = np.asarray(g["participated"])
-            self.inner.sizes[ns] = self.sizes[nu]
-            self.inner.kappas[ns] = self.kappas[nu]
-            if self.hists is not None:
-                if self.inner.hists is None:
-                    self.inner.hists = np.zeros((self.C,
-                                                 self.hists.shape[1]))
-                self.inner.hists[ns] = self.hists[nu]
-            self.inner.has_hist[ns] = self.has_hist[nu]
+        with TraceAnnotation("osafl.slot_reset", rows=int(ns.size)):
+            if ns.size:
+                self._reset_slots(ns, np.asarray(users, np.int64).ravel()[
+                    res.newly])
         return res
+
+    def _reset_slots(self, ns: np.ndarray, nu: np.ndarray) -> None:
+        """Load the newly seated slots ``ns`` (users ``nu``): one call of the
+        slot-reset program, one fetch of the gathered host-side table."""
+        reset = np.zeros(self.C, bool)
+        reset[ns] = True
+        tables = {k: self.tables[k] for k in self.tables.keys()}
+        inner = self.inner
+        if self.is_osafl:
+            inner.d_buffer, st, seated = self._slot_reset(
+                inner.d_buffer, inner.init_row(), reset, self.pool.slot_user,
+                {"participated": inner.participated,
+                 "lam_prev": inner._lam_prev}, tables)
+            inner.participated, inner._lam_prev = (st["participated"],
+                                                   st["lam_prev"])
+            ls = np.array(inner.last_scores)
+            ls[ns] = np.asarray(seated["scores"])[ns]
+            inner.last_scores = ls
+        else:
+            inner.buffer, _, seated = self._slot_reset(
+                inner.buffer, inner.init_row(), reset, self.pool.slot_user,
+                {}, tables)
+            inner.participated[ns] = np.asarray(seated["participated"])[ns]
+            inner.sizes[ns] = self.sizes[nu]
+            inner.kappas[ns] = self.kappas[nu]
+            if self.hists is not None:
+                if inner.hists is None:
+                    inner.hists = np.zeros((self.C, self.hists.shape[1]))
+                inner.hists[ns] = self.hists[nu]
+            inner.has_hist[ns] = self.has_hist[nu]
 
     # -- the round -----------------------------------------------------------
     def round_stacked(self, d_new, active, **meta):

@@ -192,6 +192,78 @@ def test_baseline_meta_carries_across_eviction():
     assert srv.inner.sizes[s] == 10.0 and srv.inner.kappas[s] == 3.0
 
 
+# admissions at U=8, C=4 seating k = 0, 1, C, 1, 3 users (flat and K=2
+# alike): free slots, FIFO evictions, an explicit eviction and the
+# readmission of user 1, with rounds between them so that the carried
+# tables hold values no fresh slot has
+ADMISSIONS = ([], [1], "round", [2, 3, 4, 5], "round", ("evict", [4]),
+              [1, 5], "round", [0, 6, 7])
+ADMITTED_K = [0, 1, 4, 1, 3]
+
+
+def _slot_state(srv) -> dict:
+    inner = srv.inner
+    st = {"participated": inner.participated}
+    if srv.is_osafl:
+        st.update(buffer=inner.d_buffer, lam_prev=inner._lam_prev,
+                  last_scores=inner.last_scores)
+    else:
+        st["buffer"] = inner.buffer
+    return {k: np.array(v) for k, v in st.items()}
+
+
+@pytest.mark.parametrize("alg,K", [("osafl", 0), ("fedavg", 0), ("osafl", 2)],
+                         ids=["osafl", "fedavg", "osafl-hier2"])
+def test_admission_matches_row_scatter_replay(alg, K):
+    """Every admission leaves the slot buffer, ``participated``,
+    ``lam_prev`` and ``last_scores`` bit-identical to a numpy replay of the
+    row scatter ``x.at[slots].set(values)``: the refresh row into each
+    newly seated slot, the seated users' carried tables into its slot
+    vectors, every other slot untouched."""
+    srv = _sparse_server(alg, U=8, C=4, seed=1, num_clusters=K)
+    N = int(srv.w.shape[0])
+    rng = np.random.default_rng(0)
+    ks = []
+    for step in ADMISSIONS:
+        if step == "round":
+            d = rng.normal(size=(4, N)).astype(np.float32)
+            srv.round_stacked(jnp.asarray(d), srv.pool.slot_user >= 0)
+            continue
+        if isinstance(step, tuple):
+            srv.pool.evict(step[1])
+            continue
+        want = _slot_state(srv)
+        tables = {k: np.array(srv.tables[k]) for k in srv.tables.keys()}
+        row = np.array(srv.inner.init_row())
+        res = srv.admit(step)
+        ns = res.slots[res.newly]
+        nu = np.asarray(step, np.int64)[res.newly]
+        ks.append(int(ns.size))
+        want["buffer"][ns] = row
+        want["participated"][ns] = tables["participated"][nu]
+        if srv.is_osafl:
+            want["lam_prev"][ns] = tables["lam_prev"][nu]
+            want["last_scores"][ns] = tables["scores"][nu]
+        got = _slot_state(srv)
+        for k, v in want.items():
+            assert got[k].dtype == v.dtype, (step, k)
+            np.testing.assert_array_equal(got[k], v, err_msg=f"{step} {k}")
+    assert ks == ADMITTED_K
+
+
+@pytest.mark.parametrize("alg", ["osafl", "fedavg"])
+def test_admissions_of_any_k_share_one_program(alg):
+    """The admission write compiles once: seating 1, C, 1 and 3 users,
+    with rounds in between, reuses one executable of the slot-reset
+    program."""
+    srv = _sparse_server(alg, U=8, C=4)
+    N = int(srv.w.shape[0])
+    for users in ([1], [2, 3, 4, 5], [1, 5], [0, 6, 7]):
+        srv.admit(users)
+        srv.round_stacked(jnp.ones((4, N), jnp.float32), np.ones(4, bool))
+    assert srv._slot_reset._cache_size() == 1
+
+
 @pytest.mark.parametrize("alg,backend", [("osafl", "python"),
                                          ("fednova", "stacked")])
 def test_sparse_harness_churn_runs(alg, backend):

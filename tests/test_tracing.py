@@ -7,8 +7,9 @@
   it in the ``op_name`` of its instructions, and no instruction names two.
 * Host spans: a ``jax.profiler`` trace of ``repro.harness.run`` on the
   sparse-cohort dispatch round holds one ``osafl.round`` span a round,
-  carrying its round index, with each phase span inside it; the fused
-  round's dispatches carry ``osafl.dispatch``.
+  carrying its round index, with each phase span inside it, and one
+  ``osafl.slot_reset`` span per admission, counting the slots it reset in a
+  ``rows`` stat; the fused round's dispatches carry ``osafl.dispatch``.
 * The score kernel's stable name.
 """
 from __future__ import annotations
@@ -155,8 +156,18 @@ _TRACE_CHILD = textwrap.dedent("""
     import dataclasses, glob, json, sys
     import jax
     from jax.profiler import ProfileData
+    from repro.core.cohort import SparseCohortServer
     from repro.harness import ExperimentConfig, run
 
+    newly = []                       # users newly seated, per admit() call
+    admit = SparseCohortServer.admit
+
+    def counting_admit(self, users):
+        res = admit(self, users)
+        newly.append(int(res.newly.sum()))
+        return res
+
+    SparseCohortServer.admit = counting_admit
     xc = ExperimentConfig(**json.loads(sys.argv[2]))
     fused = dataclasses.replace(xc, cohort_size=0, participation=1.0,
                                 round_backend="fused")
@@ -166,7 +177,7 @@ _TRACE_CHILD = textwrap.dedent("""
         run("osafl", xc)
         run("osafl", fused)
     path = glob.glob(sys.argv[1] + "/**/*.xplane.pb", recursive=True)[0]
-    spans = []
+    spans, resets = [], []
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -174,6 +185,10 @@ _TRACE_CHILD = textwrap.dedent("""
                     if ev.name.startswith("osafl."):
                         spans.append([ev.name, ev.start_ns, ev.duration_ns,
                                       dict(ev.stats).get("step_num")])
+                    if ev.name == "osafl.slot_reset":
+                        resets.append([ev.start_ns, ev.duration_ns,
+                                       dict(ev.stats).get("rows")])
+    print(json.dumps({"slot_resets": sorted(resets), "newly": newly}))
     print(json.dumps(spans))
 """)
 
@@ -184,10 +199,10 @@ ROUND_PHASES = ("osafl.admit", "osafl.arrivals", "osafl.request_draw",
 
 
 @pytest.fixture(scope="module")
-def traced_spans(tmp_path_factory):
-    """The ``osafl.*`` host spans of a profiled 2-round cohort run and a
-    2-round fused run, traced in a child process of its own (the profiler
-    is one per process) under a time limit."""
+def traced_child(tmp_path_factory):
+    """The output lines of a profiled 2-round cohort run and a 2-round
+    fused run, traced in a child process of its own (the profiler is one
+    per process) under a time limit."""
     xc = dataclasses.replace(XC, num_clients=16, cohort_size=8,
                              participation=0.5)
     env = dict(os.environ, JAX_PLATFORMS="cpu",
@@ -199,7 +214,22 @@ def traced_spans(tmp_path_factory):
          json.dumps(dataclasses.asdict(xc))],
         capture_output=True, text=True, env=env, timeout=300)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    return json.loads(proc.stdout.strip().splitlines()[-1])
+    return [json.loads(ln) for ln in proc.stdout.strip().splitlines()[-2:]]
+
+
+@pytest.fixture(scope="module")
+def traced_spans(traced_child):
+    """The ``osafl.*`` host spans of the traced runs: name, start, duration
+    and the ``step_num`` stat."""
+    return traced_child[1]
+
+
+@pytest.fixture(scope="module")
+def traced_slot_resets(traced_child):
+    """The ``osafl.slot_reset`` spans of the traced cohort run (start,
+    duration, ``rows`` stat) in time order, and the number of users each
+    ``SparseCohortServer.admit`` call seated anew, in call order."""
+    return traced_child[0]
 
 
 def test_trace_holds_a_round_span_per_round_with_its_index(traced_spans):
@@ -220,3 +250,21 @@ def test_each_phase_span_lies_inside_its_round(traced_spans):
 
 def test_fused_dispatches_carry_their_span(traced_spans):
     assert sum(s[0] == "osafl.dispatch" for s in traced_spans) == 2
+
+
+def test_each_admission_resets_its_newly_seated_slots_in_one_span(
+        traced_spans, traced_slot_resets):
+    """One ``osafl.slot_reset`` span per admission: the set-up's seating of
+    the first residents, then one inside each round's ``osafl.admit``, its
+    ``rows`` stat the number of users that call seated anew."""
+    resets = traced_slot_resets["slot_resets"]
+    assert [r[2] for r in resets] == traced_slot_resets["newly"]
+    rounds = [s for s in traced_spans if s[0] == "osafl.round"]
+    admits = [s for s in traced_spans if s[0] == "osafl.admit"]
+    assert len(resets) == len(rounds) + 1
+    for _, start, dur, _ in rounds:
+        (adm,) = [a for a in admits
+                  if start <= a[1] and a[1] + a[2] <= start + dur]
+        inside = [r for r in resets
+                  if adm[1] <= r[0] and r[0] + r[1] <= adm[1] + adm[2]]
+        assert len(inside) == 1
