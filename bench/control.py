@@ -17,8 +17,10 @@ precision and:
 * ``label``: the reference in the program's place with every new request's
   label off by one where the request model makes it (a fault);
 * ``frozen``: the reference in the program's place with weights that never
-  change (a fault; the host-driven round's comparison reads no weights, so
-  it needs a reading there).
+  change (a fault; the host-driven round's comparison may read no weights,
+  so it needs a reading there);
+* ``no_exchange``: the reference in the program's place with the exchange
+  between chips left out (a fault, for a cell on a mesh).
 
 The last line is a JSON object with every reading. Runs on the machine it is
 started on (the chip, for the readings ``PERF.md`` gives).
@@ -56,7 +58,7 @@ def main() -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--variant", required=True,
                     choices=("program", "bf16", "half_batch", "label",
-                             "frozen"))
+                             "frozen", "no_exchange"))
     ap.add_argument("--seeds", required=True,
                     help="comma-separated seeds")
     args = ap.parse_args()

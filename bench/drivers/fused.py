@@ -28,6 +28,11 @@ from bench.yardstick.reference import round_kappas, run_reference
 from bench.yardstick.start import start
 
 
+def numbers(workload: dict) -> set:
+    """The names of the numbers this driver compares for ``workload``."""
+    return {"loss_gap", "step_gap", "change_gap"}
+
+
 def build(ctx):
     """The engine and its first carry, as ``repro.harness.run`` builds them
     for a run of the one-dispatch round (``build_fused_engine``,

@@ -16,12 +16,13 @@ per per-layer metric ``bench/metrics/<metric>.py``.
 A run: the driver's set-up (its first rounds compile or load from JAX's
 persistent cache) and measured window, the peak device memory, then the
 driver's comparison of the run's first rounds with the plain reference.
-A driver module has ``run(ctx)``, ``compare_reference(ctx, res)`` and
-``trace_view(ctx, res, trace, tz)``. With ``--trace 0`` the result carries the cell's end-to-end
-metrics; with ``--trace 1`` a profiler trace of a short window gives its
-per-layer metrics. The last line on standard output is one JSON object; the
-numbers compared, each beside its limit, are the last lines on standard
-error.
+A driver module has ``numbers(workload)`` (the names of the numbers it
+compares), ``run(ctx)``, ``compare_reference(ctx, res)`` and
+``trace_view(ctx, res, trace, tz)``. With ``--trace 0`` the result carries
+the cell's end-to-end metrics; with ``--trace 1`` a profiler trace of a
+short window gives its per-layer metrics. The last line on standard output
+is one JSON object; the numbers compared, each beside its limit, are the
+last lines on standard error.
 """
 import time
 

@@ -11,8 +11,6 @@ from bench.run import BENCH, ROOT, cell_spec, load_json, load_module, \
 from bench.yardstick import trace as tz
 
 MANIFEST = load_json(ROOT / "BENCHMARK.json")
-NUMBERS = {"fused": {"loss_gap", "step_gap", "change_gap"},
-           "harness": {"loss_gap", "participants_gap"}}
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 
 
@@ -81,9 +79,9 @@ def test_manifest_resolves():
         assert set(conf["reduced"]) <= set(cfg)
     for w in MANIFEST["workloads"]:
         spec = cell_spec(w["name"])
-        assert (BENCH / "drivers" / f"{spec.workload['driver']}.py").is_file()
-        assert set(spec.workload["limits"]) == NUMBERS[
-            spec.workload["driver"]]
+        driver = load_module(BENCH / "drivers" /
+                             f"{spec.workload['driver']}.py")
+        assert set(spec.workload["limits"]) == driver.numbers(spec.workload)
         assert len(w["why"]) <= 200
     for m in MANIFEST["per_layer"]:
         assert m["moves"] in e2e

@@ -24,6 +24,9 @@ generator up after the clients' systems. Each round t:
    d_u / C;
 6. evaluation: cross-entropy of w on the evaluation set.
 
+Where the traffic runs on a ``mesh``, its client axes (``pod``, ``data``)
+split the slots into equal blocks, one a chip; the round is the same.
+
 With ``cohort_size`` 0 every user holds its own slot for the whole run and
 trains every round (the dense round). The model runs at "highest" matmul
 precision in float32, or in ``dtype`` (the control lowers it to bfloat16).
@@ -32,13 +35,25 @@ from __future__ import annotations
 
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from bench.yardstick.reference import model_fns
+from bench.yardstick.reference import leaf_sizes, model_fns
 from bench.yardstick.start import features, start
 
-FAULTS = ("half_batch", "label", "frozen")
+FAULTS = ("half_batch", "label", "frozen", "no_exchange")
+CLIENT_AXES = ("pod", "data")
+
+
+def chip_blocks(traffic: dict) -> int:
+    """The number of equal blocks the mesh's client axes split the slots
+    into (1 without a mesh)."""
+    m = traffic.get("mesh")
+    if m is None:
+        return 1
+    return int(np.prod([n for n, a in zip(m["shape"], m["axes"])
+                        if a in CLIENT_AXES]))
 
 
 def run_cohort_reference(cfg: dict, traffic: dict, model, seed: int,
@@ -46,9 +61,12 @@ def run_cohort_reference(cfg: dict, traffic: dict, model, seed: int,
                          fault: Optional[str] = None) -> dict:
     """The first ``rounds`` rounds of the run of ``seed``; per round the
     evaluation ``loss`` and the number of ``participants`` (slots that
-    trained). ``fault`` plants one of ``FAULTS``: local SGD on half of each
-    batch, every new request's label off by one, or weights that never
-    change."""
+    trained), the flat weights ``w`` before round 0 and after each round
+    (float32 host arrays) and their leaf ``sizes``. ``fault`` plants one of
+    ``FAULTS``: local SGD on half of each batch, every new request's label
+    off by one, weights that never change, or, on a mesh, the exchange
+    between chips left out (the first chip aggregates its own block of
+    slots as if it were the whole pool)."""
     if cfg["use_resource_opt"]:
         raise ValueError("the host-driven reference fixes kappa_u at "
                          "kappa_max; the configuration asks for the solve")
@@ -58,13 +76,19 @@ def run_cohort_reference(cfg: dict, traffic: dict, model, seed: int,
     resample = C0 > 0 and (C < U or traffic["participation"] < 1.0)
     B, kmax, lr = cfg["batch"], cfg["kappa_max"], cfg["local_lr"]
     E, ds = cfg["arrivals"], cfg["dataset"]
+    blocks = chip_blocks(traffic)
+    if fault == "no_exchange" and blocks == 1:
+        raise ValueError("the no_exchange fault needs a mesh of chips")
     st = start(cfg, seed, U, C0, traffic["eval_samples"], fault=fault)
     pool, fifo, rng = st.pool, st.fifo, st.rng
-    f = model_fns(cfg, model, model.init(st.key), dtype)
+    params0 = model.init(st.key)
+    f = model_fns(cfg, model, params0, dtype)
+    set_row = jax.jit(lambda m, s, r: m.at[s].set(r), donate_argnums=0)
     w = f.w0
     buf = jnp.zeros((C, w.shape[0]), dtype)
     test_x, test_y = f.batch_x(st.test_x), jnp.asarray(st.test_y)
-    out = {"loss": [], "participants": []}
+    out = {"loss": [], "participants": [], "w": [np.asarray(w, np.float32)],
+           "sizes": leaf_sizes(params0)}
     for _ in range(rounds):
         picked = np.ones(C, bool)
         if resample:
@@ -87,7 +111,6 @@ def run_cohort_reference(cfg: dict, traffic: dict, model, seed: int,
         j = rng.integers(0, size.reshape(C, 1, 1), size=(C, kmax, B))
         slots = (fifo.head[:, None, None] + j) % fifo.cap[:, None, None]
         params = f.unflatten(w)
-        rows = []
         for s in np.flatnonzero(active):
             p = params
             for k in range(kmax):
@@ -97,13 +120,14 @@ def run_cohort_reference(cfg: dict, traffic: dict, model, seed: int,
                 if ds == 1:
                     xb = features(st.cat, cohort[s], xb)
                 p = f.sgd(p, f.batch_x(xb), jnp.asarray(yb))
-            rows.append((w - f.flatten(p)) / jnp.asarray(lr * kmax, dtype))
-        if rows:
-            buf = buf.at[jnp.asarray(np.flatnonzero(active))].set(
-                jnp.stack(rows))
-        if fault != "frozen":
+            buf = set_row(buf, s, (w - f.flatten(p))
+                          / jnp.asarray(lr * kmax, dtype))
+        if fault == "no_exchange":
+            w = f.server_step(w, buf[:C // blocks], C // blocks)
+        elif fault != "frozen":
             w = f.server_step(w, buf, C)
         loss, _ = f.evaluate(w, test_x, test_y)
         out["loss"].append(float(loss))
         out["participants"].append(int(active.sum()))
+        out["w"].append(np.asarray(w, np.float32))
     return out

@@ -15,10 +15,11 @@ numbers, each against the limit the cell's workload file states:
 A leaf whose reference step is under a thousandth of the median leaf's
 moves by rounding alone and is left out of both norm gaps.
 
-Where only the run's history can be read (the host-driven round, whose
-weights stay inside ``repro.harness.run``), two: ``loss_gap`` as above, and
-``participants_gap``, the largest difference over the compared rounds in the
-number of clients that trained, which has to be 0.
+For the host-driven round, whose weights stay inside ``repro.harness.run``,
+the run's history gives ``loss_gap`` as above and ``participants_gap``, the
+largest difference over the compared rounds in the number of clients that
+trained, which has to be 0; where its driver reads the weights too, the two
+norm gaps join them.
 """
 from __future__ import annotations
 

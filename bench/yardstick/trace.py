@@ -1,17 +1,18 @@
 """Reduction of a JAX profiler trace to the numbers the per-layer metrics
 read.
 
-``load`` turns an ``.xplane.pb`` into plain lists: for each TPU device the
-operations of its "XLA Ops" line and the program executions of its "XLA
-Modules" line as ``[start_ns, duration_ns, name]``, and the benchmark's host
-spans (``segment``, ``device_get``, ``check_outputs``, ``harness_run``) as
-``[name, start_ns, duration_ns]``. A program keeps its name without the
-execution id the trace appends in parentheses. An operation keeps its short HLO name
-(``%while.43``), except a Pallas kernel (``custom_call_target=
-"tpu_custom_call"``), which keeps its whole text, operand shapes included:
-the trace names the score kernel ``%branch_0_fun.N`` after the platform
-branch it is lowered in, so its operands are what identify it. The same
-plain form is what ``bench/tests/data`` keeps of a recorded trace.
+``load`` turns an ``.xplane.pb`` into plain lists: for each TPU device, in
+the order of the devices' ids, the operations of its "XLA Ops" line and the
+program executions of its "XLA Modules" line as ``[start_ns, duration_ns,
+name]``, and the benchmark's host spans (``segment``, ``device_get``,
+``check_outputs``, ``harness_run``) as ``[name, start_ns, duration_ns]``. A
+program keeps its name without the execution id the trace appends in
+parentheses. An operation keeps its short HLO name (``%while.43``), except
+a Pallas kernel (``custom_call_target="tpu_custom_call"``), which keeps its
+whole text, operand shapes included: the trace names the score kernel
+``%branch_0_fun.N`` after the platform branch it is lowered in, so its
+operands are what identify it. The same plain form is what
+``bench/tests/data`` keeps of a recorded trace.
 
 The XLA Ops line nests: a ``while`` operation spans the operations of its
 body. Busy time is the union of the intervals, so nesting does not count
@@ -25,7 +26,7 @@ import re
 KERNEL_MARK = 'custom_call_target="tpu_custom_call"'
 SPANS = ("segment", "device_get", "check_outputs", "harness_run")
 _RUN_ID = re.compile(r"\(\d+\)$")
-_DEVICE = re.compile(r"^/device:TPU:\d+$")
+_DEVICE = re.compile(r"^/device:TPU:(\d+)$")
 
 
 def load(path: str) -> dict:
@@ -33,7 +34,7 @@ def load(path: str) -> dict:
 
     pd = ProfileData.from_file(path)
     devices, modules, spans = [], [], []
-    for plane in pd.planes:
+    for plane in sorted(pd.planes, key=_device_id):
         if _DEVICE.match(plane.name):
             ops, mods = [], []
             for line in plane.lines:
@@ -59,6 +60,11 @@ def load(path: str) -> dict:
                                       float(ev.duration_ns)])
     spans.sort(key=lambda s: s[1])
     return {"devices": devices, "modules": modules, "spans": spans}
+
+
+def _device_id(plane) -> int:
+    m = _DEVICE.match(plane.name)
+    return int(m.group(1)) if m else -1
 
 
 def window(tr: dict) -> tuple:
